@@ -31,7 +31,6 @@ BENCH_pq.json as ``*_dist`` cells.
 
 import os
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import json  # noqa: E402

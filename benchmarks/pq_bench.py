@@ -60,7 +60,7 @@ def make_impl_engine(impl: str, width: int, *, lanes: int = DEFAULT_LANES,
     adaptive controller's decision cadence in ticks (its deployment
     knob: decisions per window cost one host round-trip).  `backend`
     is the spec-level kernel backend (jnp | pallas | pallas_interpret |
-    auto); None keeps the config default ("auto", honoring PQ_BACKEND).
+    auto); None keeps the base config's backend.
     """
     controller = None
     if window is not None:
@@ -72,34 +72,46 @@ def make_impl_engine(impl: str, width: int, *, lanes: int = DEFAULT_LANES,
         backend=backend))
 
 
+def mix_arrays(width: int, n_add: int, n_rm: int, ticks: int, rng,
+               key_dist: str, *, resident: int = WARM_ELEMENTS):
+    """The p-coin mix workload as host arrays: ``(keys [T, W] f32,
+    vals [T, W] i32, mask [T, W] bool)``, the first ``n_add`` slots of
+    each tick live.
+
+    key_dist "des" is the hold model: a virtual clock advances by the
+    key span that ``n_rm`` removals consume from ``resident`` keys
+    spread over [0, KEY_HI), and new keys land an exponential increment
+    above it (clustered just above the current minimum).  "uniform"
+    draws over the whole key space.
+    """
+    ak = np.full((ticks, width), np.inf, np.float32)
+    av = np.broadcast_to(np.arange(width, dtype=np.int32),
+                         (ticks, width)).copy()
+    mask = np.zeros((ticks, width), bool)
+    mask[:, :n_add] = True
+    step = KEY_HI / max(resident, 1)
+    lo = 0.0
+    for t in range(ticks):
+        if key_dist == "des":
+            lo += n_rm * step
+            ak[t, :n_add] = lo + rng.exponential(step * 8, n_add)
+        else:
+            ak[t, :n_add] = rng.uniform(0, KEY_HI, n_add)
+    return ak, av, mask
+
+
 def gen_mix_batches(width: int, n_add: int, n_rm: int, ticks: int, rng,
                     key_dist: str):
     """Pre-generated per-tick op batches of the p-coin mix workload
-    (host work out of every timed loop).  SHARED by bench_mix and
-    benchmarks/dist_bench.py: the dist cells are only comparable to
-    their in-process single-device reference because both drivers
-    consume bit-identical streams from this one generator.
-
-    key_dist "des" advances a virtual clock with the removal rate (the
-    hold model: new keys cluster just above the current minimum);
-    "uniform" draws over the whole key space.
+    (host work out of every timed loop) at the paper's warm depth.
+    SHARED by bench_mix and benchmarks/dist_bench.py: the dist cells are
+    only comparable to their in-process single-device reference because
+    both drivers consume bit-identical streams from this one generator
+    (:func:`mix_arrays` documents the key distributions).
     """
-    lo = 0.0
-    batches = []
-    for t in range(ticks):
-        ak = np.full((width,), np.inf, np.float32)
-        av = np.arange(width, dtype=np.int32)
-        mask = np.zeros((width,), bool)
-        if key_dist == "des":
-            lo += n_rm * KEY_HI / max(WARM_ELEMENTS, 1)
-            ak[:n_add] = lo + rng.exponential(KEY_HI / WARM_ELEMENTS * 8,
-                                              n_add)
-        else:
-            ak[:n_add] = rng.uniform(0, KEY_HI, n_add)
-        mask[:n_add] = True
-        batches.append((jnp.asarray(ak), jnp.asarray(av),
-                        jnp.asarray(mask)))
-    return batches
+    ak, av, mask = mix_arrays(width, n_add, n_rm, ticks, rng, key_dist)
+    return [(jnp.asarray(k), jnp.asarray(v), jnp.asarray(m))
+            for k, v, m in zip(ak, av, mask)]
 
 
 def _warm(eng, rng):

@@ -13,8 +13,9 @@ Figures map (DESIGN.md §10):
   dry-run -> bench_dryrun_summary (reads artifacts/dryrun JSONs)
 
 CPU wall-times characterize *algorithmic* behavior (relative throughput
-across designs, path breakdowns); TPU performance claims live in the
-roofline analysis (EXPERIMENTS.md §Roofline/§Perf), not here.
+across designs, path breakdowns); they are never TPU numbers.  The dist
+and serve cells run as child jax processes, so this harness runs on the
+CPU only; chip_smoke.py is the path that runs on the chip.
 """
 
 from __future__ import annotations
@@ -128,7 +129,7 @@ def bench_kernels() -> None:
     v = jnp.asarray(rng.integers(0, 1 << 20, (rows, n)), jnp.int32)
     f = jnp.zeros((rows, n), jnp.int32)
 
-    pallas_bk = ops.resolve_backend("pallas")
+    pallas_bk = ops.resolve_backend("pallas_interpret")
     jnp_bk = ops.resolve_backend("jnp")
     for name, fn in (
         ("bitonic_pallas",
@@ -191,88 +192,61 @@ def bench_dryrun_summary() -> None:
               f"|fits={r['memory']['fits_hbm']}")
 
 
-def _run_dist_bench(required: bool):
-    """benchmarks/dist_bench.py in a subprocess (device count locks at
-    first jax init, so the 8-fake-device cells can never share this
-    process).  Returns the parsed DIST_CELLS_JSON payload; `required`
-    raises instead of emitting a failure line, so the smoke bench (whose
-    cells the regression gate tracks) can never silently drop the
-    multi-device trajectory."""
+def _run_bench_child(script: str, csv_prefix: str, marker: str) -> dict:
+    """Run ``script`` in a child process and return its ``marker`` JSON.
+
+    The dist and serve benches force host device counts, which lock at
+    the first jax init, so they cannot share this process.  A child that
+    needs the chip cannot run beside a parent that holds it, so this
+    refuses once the parent's jax is on an accelerator.  Any failure of
+    the child raises: a run never drops cells silently."""
     import os
     import subprocess
     import sys
+
+    import jax
+    platform = jax.default_backend()
+    if platform != "cpu":
+        raise RuntimeError(
+            f"{script} runs as a child jax process, but this process "
+            f"already holds the {platform!r} backend; run.py's child "
+            "benches are CPU-only (chip_smoke.py is the chip path)")
     env = {**os.environ,
            "PYTHONPATH": "src:" + os.environ.get("PYTHONPATH", ".")}
     proc = subprocess.run(
-        [sys.executable, "benchmarks/dist_bench.py"],
+        [sys.executable, script],
         capture_output=True, text=True, timeout=2400, env=env)
     if proc.returncode != 0:
-        msg = (proc.stderr.strip().splitlines()[-1][:200]
-               if proc.stderr else "?")
-        if required:
-            raise RuntimeError(
-                f"dist bench failed (exit {proc.returncode}): {msg}\n"
-                f"{proc.stderr[-4000:]}")
-        _emit("dist_bench_failed", 0.0, msg[:80])
-        return None
+        raise RuntimeError(
+            f"{script} failed (exit {proc.returncode}):\n"
+            f"{proc.stderr[-4000:]}")
     for line in proc.stdout.strip().splitlines():
-        if line.startswith("dist_"):
+        if line.startswith(csv_prefix):
             print(line)
     for line in proc.stdout.splitlines():
-        if line.startswith("DIST_CELLS_JSON "):
-            return json.loads(line[len("DIST_CELLS_JSON "):])
-    if required:
-        raise RuntimeError("dist bench produced no DIST_CELLS_JSON line")
-    return None
+        if line.startswith(marker + " "):
+            return json.loads(line[len(marker) + 1:])
+    raise RuntimeError(f"{script} produced no {marker} line")
 
 
-def bench_dist_elimination() -> None:
+def bench_dist_elimination() -> dict:
     """Elimination = communication avoidance (the paper's thesis at pod
     scale): the lanes-over-devices DistShardedQueue with pre-route
     elimination adaptive vs forced off, plus the single-device
-    sharded_L8 reference, 8 fake devices in a subprocess."""
-    _run_dist_bench(required=False)
+    sharded_L8 reference — benchmarks/dist_bench.py, 8 forced host
+    devices.  Returns its DIST_CELLS_JSON payload."""
+    return _run_bench_child("benchmarks/dist_bench.py", "dist_",
+                            "DIST_CELLS_JSON")
 
 
-def _run_serve_bench(required: bool):
-    """benchmarks/serve_bench.py in a subprocess (it forces 2 host
-    devices, which must not leak into this process's jax).  Returns the
-    parsed SERVE_CELLS_JSON payload: the serving engine's SLA cells
-    (time-to-serve quantiles in SIMULATED ticks — deterministic, so the
-    gate sees latency-distribution drift, not runner noise)."""
-    import os
-    import subprocess
-    import sys
-    env = {**os.environ,
-           "PYTHONPATH": "src:" + os.environ.get("PYTHONPATH", ".")}
-    proc = subprocess.run(
-        [sys.executable, "benchmarks/serve_bench.py"],
-        capture_output=True, text=True, timeout=2400, env=env)
-    if proc.returncode != 0:
-        msg = (proc.stderr.strip().splitlines()[-1][:200]
-               if proc.stderr else "?")
-        if required:
-            raise RuntimeError(
-                f"serve bench failed (exit {proc.returncode}): {msg}\n"
-                f"{proc.stderr[-4000:]}")
-        _emit("serve_bench_failed", 0.0, msg[:80])
-        return None
-    for line in proc.stdout.strip().splitlines():
-        if line.startswith("serve_"):
-            print(line)
-    for line in proc.stdout.splitlines():
-        if line.startswith("SERVE_CELLS_JSON "):
-            return json.loads(line[len("SERVE_CELLS_JSON "):])
-    if required:
-        raise RuntimeError("serve bench produced no SERVE_CELLS_JSON line")
-    return None
-
-
-def bench_serve_sla() -> None:
+def bench_serve_sla() -> dict:
     """SLA cells of the overload-robust serving engine: steady /
-    overload / bursty / chaos-kill regimes, quantiles in simulated
-    ticks (benchmarks/serve_bench.py, subprocess)."""
-    _run_serve_bench(required=False)
+    overload / bursty / chaos-kill regimes, time-to-serve quantiles in
+    SIMULATED ticks (deterministic, so the gate sees latency-distribution
+    drift, not runner noise) — benchmarks/serve_bench.py, 2 forced host
+    devices.  Returns its SERVE_CELLS_JSON payload."""
+    return _run_bench_child("benchmarks/serve_bench.py", "serve_",
+                            "SERVE_CELLS_JSON")
 
 
 def bench_straggler() -> None:
@@ -479,9 +453,8 @@ def bench_smoke_json(out_path: str = "BENCH_pq.json",
           f"{tuner_demo['speedup']:.2f}x")
 
     # multi-device cells (subprocess, 8 forced host devices): the dist
-    # engine vs the single-device reference on the same workload —
-    # REQUIRED, so CI can never silently drop the dist trajectory
-    dist = _run_dist_bench(required=True)
+    # engine vs the single-device reference on the same workload
+    dist = bench_dist_elimination()
     dist_cells = dist["cells"]
     quality.update(dist.get("quality", {}))
     for cname, cell in dist_cells.items():
@@ -490,8 +463,8 @@ def bench_smoke_json(out_path: str = "BENCH_pq.json",
             _emit(f"smoke_{name}_{cname}", us, "us_per_tick")
 
     # serving SLA cells (subprocess, 2 forced host devices): quantiles
-    # in simulated ticks — REQUIRED for the same reason as dist
-    serve = _run_serve_bench(required=True)
+    # in simulated ticks
+    serve = bench_serve_sla()
     serve_cells = serve["cells"]
     for cname, cell in serve_cells.items():
         results[cname] = cell
@@ -594,43 +567,9 @@ def bench_smoke_json(out_path: str = "BENCH_pq.json",
     print(f"# wrote {out_path}")
 
 
-def bench_accel() -> None:
-    """Optional accelerator leg (CI job bench-accel): the fused lane
-    megakernel under a REAL pallas backend (Mosaic on TPU, Triton on
-    GPU) priced against the jnp path on the same chip, roofline records
-    attached.  Skips CLEANLY — one line, exit 0 — when the runtime only
-    has CPU, so the job can be enabled on any runner pool without going
-    red (on CPU the megakernel's pallas path is interpret-mode anyway,
-    a correctness tool, not a perf claim; DESIGN.md §13)."""
-    import jax
-    from benchmarks.pq_bench import bench_mix
-    dev = jax.default_backend()
-    if dev == "cpu":
-        print("# accel bench: jax.default_backend()=cpu — no accelerator, "
-              "skipping cleanly")
-        return
-    for impl, kw in (("pqe", {}), ("sharded", dict(lanes=8))):
-        for bk in ("jnp", "pallas"):
-            r = bench_mix(impl, SMOKE_GRID_WIDTH, 0.3, ticks=20,
-                          key_dist="des", settle=40, roofline=True,
-                          backend=bk, **kw)
-            _emit(f"accel_{dev}_{impl}_{bk}", r["us_per_tick"],
-                  "us_per_tick")
-            rl = r.get("roofline")
-            if rl:
-                _emit(f"accel_{dev}_{impl}_{bk}_roofline", 0.0,
-                      f"{rl['bound']}_bound"
-                      f"|peak_bw={rl['frac_peak_bw']:.2%}"
-                      f"|peak_flops={rl['frac_peak_flops']:.2%}"
-                      f"|of_{rl['peak_ref']}")
-
-
 def main() -> None:
     import sys
     print("name,us_per_call,derived")
-    if "--accel" in sys.argv:
-        bench_accel()
-        return
     if "--smoke" in sys.argv:
         out = "BENCH_pq.json"
         if "--out" in sys.argv:

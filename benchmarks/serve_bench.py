@@ -28,7 +28,6 @@ into BENCH_pq.json as ``serve_*`` cells.
 
 import os
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 
 import json  # noqa: E402

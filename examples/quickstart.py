@@ -63,11 +63,11 @@ def main() -> None:
 
     print("\n== kernel backend: config, not per-call (DESIGN.md §13) ==")
     # backend selection rides the spec and resolves ONCE at engine
-    # construction — "jnp" (reference), "pallas" (fused lanes-in-grid
-    # megakernel; Mosaic on TPU, interpret elsewhere), "pallas_interpret"
-    # (the same kernel, forced interpreter execution — the off-TPU
-    # validation mode used here), or "auto" (pallas on TPU, else jnp;
-    # the PQ_BACKEND env var overrides).  Same stream, bit-identical
+    # construction — "jnp" (reference, and the chip path), "pallas"
+    # (fused lanes-in-grid megakernel via Mosaic; TPU only, and Mosaic
+    # refuses it for v5e today), "pallas_interpret" (the same kernel,
+    # interpreter-executed — the off-TPU validation mode used here), or
+    # "auto" (jnp; the PQ_BACKEND env var overrides).  Same stream, bit-identical
     # serves on any backend — that contract is CI-pinned
     # (tests/test_lane_megakernel.py).
     fused = make_engine(EngineSpec(engine="pqe", width=64, base=base,

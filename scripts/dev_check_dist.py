@@ -8,7 +8,6 @@ Run:  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
       PYTHONPATH=src python scripts/dev_check_dist.py
 """
 import os
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import numpy as np

@@ -96,7 +96,7 @@ class PQConfig:
 
     def __post_init__(self) -> None:
         # canonicalize the backend spelling eagerly: validation + the
-        # jax.default_backend() probe (for "pallas"/"auto") happen here,
+        # jax.default_backend() probe (for "pallas") happen here,
         # outside any trace, so the compiled tick's cache key carries the
         # resolved choice (dataclasses.replace re-runs this; a resolved
         # KernelBackend passes through unchanged)

@@ -48,30 +48,16 @@ def sp_rules(base: Dict[str, Tuple[str, ...]]) -> Dict[str, Tuple[str, ...]]:
 
 
 def shard_map(body, *, mesh: Mesh, in_specs, out_specs):
-    """Version-compat shard_map with replication checking disabled.
-
-    jax >= 0.6 exposes jax.shard_map(check_vma=...); older versions only
-    have jax.experimental.shard_map.shard_map(check_rep=...).  Both checks
-    reject the manual psum patterns the distributed tick uses, so they are
-    disabled uniformly.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with replication checking off: the check rejects
+    the manual psum patterns the distributed tick uses."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(shape, axes) -> Mesh:
-    """jax.make_mesh with explicit-Auto axis types where the jax version
-    supports them (axis_types landed after 0.4; Auto is the default
-    behaviour on older versions, so omitting it is equivalent)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis typed Auto."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 class _Ctx(threading.local):

@@ -9,13 +9,16 @@ network).  TPU adaptation notes (DESIGN.md §2):
   dependent control flow, no gathers.
 * The idx^stride partner exchange is expressed as a reshape to
   ``(groups, 2, stride)`` and lane-wise min/max — pure layout + vector ops,
-  no dynamic indexing, so it lowers cleanly to Mosaic.
+  no dynamic indexing.
 * Grid = rows; each row's (keys, vals, flags) triple is one VMEM-resident
   block.  N (pow2) up to 8192 keeps the working set ≤ ~96 KiB/row, far
   under the ~16 MiB VMEM budget, leaving room for double buffering.
 
 Stages are unrolled statically: log2(N)·(log2(N)+1)/2 compare-exchange
 sweeps (78 for N=4096).
+
+Mosaic refuses this kernel for v5e: a ``(1, n)`` row block is not
+(8, 128)-aligned (tests/test_tpu_compile.py pins the refusal).
 """
 
 from __future__ import annotations
@@ -75,7 +78,7 @@ def _kernel(keys_ref, vals_ref, flags_ref, ok_ref, ov_ref, of_ref, *, n: int):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def bitonic_sort_kvf(keys, vals, flags, *, interpret: bool = True):
+def bitonic_sort_kvf(keys, vals, flags, *, interpret: bool):
     """Co-sort each row of (keys, vals, flags) by key ascending.
 
     Shapes: [rows, n] with n a power of two. keys f32, vals i32, flags i32.

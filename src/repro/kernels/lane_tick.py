@@ -29,11 +29,13 @@ the kernel equals the reference's cond-hoisted skip.  CI pins
 ``pallas_interpret`` equality against the jnp lane tick across the full
 repair matrix (tests/test_lane_megakernel.py).
 
-Honest caveat (DESIGN.md §13): the pass chain still contains
-``take_along_axis`` window gathers; those lower under interpret mode
-(where the equivalence legs run) but are the remaining obstacle to a
-clean Mosaic lowering on real TPU hardware — the per-op kernels
-(bitonic / merge_consume / radix_select) remain the TPU-proven pieces.
+Mosaic refuses this kernel for v5e (DESIGN.md §13), so it runs only in
+interpret mode and no engine reaches it by default: at L>1 the
+``(1, n)`` lane blocks are not (8, 128)-aligned (:func:`_lane_spec`),
+and at L=1 the ``take_along_axis`` in ``pqueue._shift_left`` is a
+gather Mosaic does not lower ("Only 2D gather is supported").  The
+per-op kernels (bitonic / merge_consume / radix_select) are refused
+too.  tests/test_tpu_compile.py pins each refusal as a strict xfail.
 
 Import note: this module imports ``repro.core.pqueue`` and is therefore
 imported LAZILY by core/pqueue.py + core/sharded.py (and deliberately not

@@ -100,7 +100,7 @@ def _kernel(ak_ref, av_ref, af_ref, bk_ref, bv_ref, bf_ref,
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def merge_sorted_kvf(ak, av, af, bk, bv, bf, *, tile: int = 256,
-                     interpret: bool = True):
+                     interpret: bool):
     """Merge sorted (INF-padded) streams a and b; ties resolve a-first.
 
     Args: ak/bk f32 sorted ascending, av/bv i32 (|v| < 2**24), af/bf i32.

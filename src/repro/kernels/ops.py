@@ -4,21 +4,28 @@ Backend selection is a RESOLVED config value, not a per-call string: the
 supported path is ``PQConfig(backend=...)`` / ``EngineSpec(backend=...)``
 (``repro.core``), which call :func:`resolve_backend` ONCE at config
 construction and thread the frozen :class:`KernelBackend` through every
-op.  Resolving eagerly (instead of the old per-call
-``jax.default_backend()`` probe inside jit tracing) makes the backend
-part of the compiled program's cache key instead of ambient global
-state.  Spellings accepted by :func:`resolve_backend`:
+op.  Resolving eagerly makes the backend part of the compiled program's
+cache key instead of ambient global state.  Spellings accepted by
+:func:`resolve_backend`:
 
-* ``"pallas"`` — pl.pallas_call kernels; Mosaic-compiled on TPU,
-  interpret-mode (kernel bodies execute as traced JAX ops) elsewhere.
-* ``"pallas_interpret"`` — pallas kernels with interpret=True forced,
-  regardless of the runtime backend (the CI equivalence legs).
-* ``"jnp"`` — pure-jnp reference path (the oracle, also the XLA-native
-  fallback).  Never touches the JAX runtime at resolve time, so configs
-  built at import time stay XLA-flag-safe.
-* ``"auto"`` — pallas on TPU, jnp elsewhere (CPU benchmarks should not
-  pay interpret-mode overhead).  The ``PQ_BACKEND`` env var overrides
-  what "auto" resolves to (the CI pallas-interpret leg forces it).
+* ``"jnp"`` — pure-jnp path: the oracle, and the path that runs on the
+  chip.  Never touches the JAX runtime at resolve time, so configs built
+  at import time stay XLA-flag-safe.
+* ``"pallas"`` — pl.pallas_call kernels compiled by Mosaic; only valid
+  on a TPU (raises elsewhere).
+* ``"pallas_interpret"`` — the same kernels with interpret=True, on any
+  platform (the off-TPU equivalence tests and the CI interpret leg).
+* ``"auto"`` — ``"jnp"`` on every platform; the ``PQ_BACKEND`` env var
+  overrides what "auto" resolves to (the CI pallas-interpret leg forces
+  it).
+
+Why "auto" never picks Pallas: no Pallas kernel here compiles for a TPU
+v5e.  Mosaic refuses the lane-tick megakernel (unaligned ``(1, n)``
+lane blocks at L>1; a non-2D ``take_along_axis`` gather at L=1), the
+bitonic sort (block alignment), the merge (operand layout) and the radix
+select (scalar stores to VMEM).  tests/test_tpu_compile.py pins each
+refusal as a strict xfail and compiles the jnp tick at deployment size
+beside them, so the PR that makes a kernel compile flips its case.
 
 The per-call ``backend=`` string kwargs on the ops below are DEPRECATED
 aliases (they warn and re-resolve per call); in-repo call sites pass the
@@ -75,11 +82,11 @@ def resolve_backend(backend) -> KernelBackend:
     """Validate + resolve a backend spelling to a :class:`KernelBackend`.
 
     Called once at config construction (``PQConfig.__post_init__`` /
-    ``factory.resolved_base``).  "jnp" and "pallas_interpret" never touch
-    the JAX runtime, so module-level configs (repro.core.config.SMALL /
-    PRODUCTION) keep the import-then-set-XLA-flags contract; only
-    "pallas"/"auto" probe ``jax.default_backend()`` — and they probe it
-    HERE, eagerly, never inside jit tracing.
+    ``factory.resolved_base``).  Only "pallas" probes
+    ``jax.default_backend()`` — HERE, eagerly, never inside jit tracing —
+    and it raises off-TPU: interpret mode is asked for by name
+    ("pallas_interpret"), never reached by fallback.  "auto" is "jnp"
+    (module docstring: no Pallas kernel compiles for v5e).
     """
     if isinstance(backend, KernelBackend):
         return backend
@@ -96,22 +103,26 @@ def resolve_backend(backend) -> KernelBackend:
                     f"PQ_BACKEND={env!r} must be one of "
                     f"{tuple(b for b in BACKENDS if b != 'auto')}")
             backend = env
+        else:
+            backend = "jnp"
     if backend == "jnp":
         return KernelBackend("jnp")
     if backend == "pallas_interpret":
         return KernelBackend("pallas", interpret=True)
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
-        if backend == "jnp":
-            return KernelBackend("jnp")
-    # "pallas": Mosaic on TPU, interpret-mode elsewhere
-    return KernelBackend("pallas", interpret=jax.default_backend() != "tpu")
+    platform = jax.default_backend()
+    if platform != "tpu":
+        raise ValueError(
+            f'backend "pallas" compiles with Mosaic and needs a TPU (this '
+            f'process runs on {platform!r}); use "pallas_interpret" for the '
+            f'interpreter or "jnp"')
+    return KernelBackend("pallas")
 
 
 def _coerce(backend) -> KernelBackend:
     """Per-op backend arg -> KernelBackend.  ``None`` (the default)
-    resolves "auto" silently; strings are the deprecated per-call alias
-    and warn — the supported path is the config-level ``KernelBackend``.
+    resolves "auto" silently (jnp unless ``PQ_BACKEND`` says otherwise);
+    strings are the deprecated per-call alias and warn — the supported
+    path is the config-level ``KernelBackend``.
     """
     if isinstance(backend, KernelBackend):
         return backend

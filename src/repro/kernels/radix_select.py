@@ -67,7 +67,7 @@ def _kernel(keys_ref, k_ref, tau_ref, nbelow_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def radix_select_threshold(keys, k, *, interpret: bool = True):
+def radix_select_threshold(keys, k, *, interpret: bool):
     """(tau, n_below) such that tau is the k-th smallest key of `keys`.
 
     keys: [L] f32 (INF-padded) or [NB, BCAP] bucket rows (flattened

@@ -7,7 +7,7 @@ letting p99 diverge has failed its users.  :func:`run_sla` drives a
 ticks, drains the backlog, flushes the retry buffer, and returns a
 record in which
 
-    arrivals == served + shed + expired        (exact, asserted)
+    arrivals == served + shed + expired        (exact, checked)
 
 — the outcome partition of DESIGN.md §8 — together with time-to-serve
 p50 / p99 / p99.9 of the SERVED class, measured on the simulated clock
@@ -94,9 +94,10 @@ def run_sla(engine: RequestEngine, n_ticks: int, *,
     """Drive ``n_ticks`` arrival rounds, then (by default) drain the
     backlog and flush the retry buffer so the partition is exact.
 
-    Returns the engine report plus the run shape; asserts the
-    conservation contract ``arrivals == served + shed + expired`` when
-    drained (with the residual classes when not).
+    Returns the engine report plus the run shape; raises
+    ``RuntimeError`` unless the conservation contract ``arrivals ==
+    served + shed + expired`` holds when drained (with the residual
+    classes when not).
     """
     for _ in range(n_ticks):
         engine.tick()
@@ -113,10 +114,9 @@ def run_sla(engine: RequestEngine, n_ticks: int, *,
     rep["n_ticks"] = n_ticks
     rep["drain_ticks"] = drain_ticks
     total = rep["served"] + rep["shed"] + rep["expired"]
-    if drain:
-        assert total == rep["arrivals"], (
+    if not drain:
+        total += rep["in_flight"] + rep["retry_pending"]
+    if total != rep["arrivals"]:
+        raise RuntimeError(
             f"outcome partition broken: {total} != {rep['arrivals']}")
-    else:
-        assert total + rep["in_flight"] + rep["retry_pending"] == \
-            rep["arrivals"]
     return rep

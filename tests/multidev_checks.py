@@ -10,6 +10,8 @@ Checks:
  4. shard_map EP MoE == local MoE (no-drop regime)
  5. sharded train_step executes on a (2,4) mesh, ZeRO+FSDP specs applied
  6. sharded decode step executes on a (2,4) mesh
+ 7. chip_smoke.py's four-device phase at a small size: dist D=4 ==
+    sharded L=8 every tick, then remove_device conserves the multiset
 
 Exit codes: 0 ok, 42 SKIP (host device count could not be forced — the
 parent pytest harness turns this into a clean skip), anything else is a
@@ -268,6 +270,26 @@ def check_sharded_decode():
     print("OK sharded_decode")
 
 
+def check_chip_smoke_dist():
+    """chip_smoke.dist_phase on 4 of the forced devices, small shapes
+    (the chip runs it at W=8192 and 5x10^5 resident keys)."""
+    from pathlib import Path
+
+    from repro.core.config import PQConfig
+
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    import chip_smoke as cs
+
+    base = PQConfig(a_max=64, r_max=64, seq_cap=512, n_buckets=16,
+                    bucket_cap=128, detach_min=4, detach_max=64,
+                    detach_init=8, chop_patience=8)
+    out = cs.dist_phase(jax.devices()[:4], width=128, lanes=8, base=base,
+                        resident=600, mix_ticks=20, chunk=5, after_ticks=4,
+                        seed=2, clock=cs.CompileClock())
+    assert out["devices_after"] == 3, out
+    print("OK chip_smoke_dist")
+
+
 if __name__ == "__main__":
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
     checks = {
@@ -277,6 +299,7 @@ if __name__ == "__main__":
         "moe": check_moe_parity,
         "train": check_sharded_train_step,
         "decode": check_sharded_decode,
+        "chip_smoke_dist": check_chip_smoke_dist,
     }
     _require_forced_devices()
     try:

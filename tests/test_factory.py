@@ -172,8 +172,66 @@ def test_pqconfig_canonicalizes_backend_string():
 
     cfg = PQConfig(a_max=W, r_max=W, backend="pallas_interpret")
     assert cfg.backend == KernelBackend("pallas", interpret=True)
-    # default is "auto"-resolved at construction, honoring PQ_BACKEND
-    assert isinstance(PQConfig(a_max=W, r_max=W).backend, KernelBackend)
+    # the default spelling is resolved at construction too
+    assert PQConfig(a_max=W, r_max=W).backend == KernelBackend("jnp")
+
+
+def test_auto_resolves_to_jnp_on_every_platform(monkeypatch):
+    """No Pallas kernel compiles for v5e (tests/test_tpu_compile.py), so
+    "auto" — and the ops' default — is the jnp tick even on a TPU."""
+    from repro.kernels import ops
+
+    monkeypatch.delenv("PQ_BACKEND", raising=False)
+    for platform in ("cpu", "tpu"):
+        monkeypatch.setattr(ops.jax, "default_backend", lambda: platform)
+        assert ops.resolve_backend("auto") == ops.KernelBackend("jnp")
+        assert ops.resolve_backend(None) == ops.KernelBackend("jnp")
+        assert ops._coerce(None) == ops.KernelBackend("jnp")
+    # on a TPU an explicit "pallas" still asks Mosaic for the kernels
+    assert ops.resolve_backend("pallas") == ops.KernelBackend("pallas")
+
+
+def test_pq_backend_env_still_steers_auto(monkeypatch):
+    from repro.kernels import ops
+
+    monkeypatch.setenv("PQ_BACKEND", "pallas_interpret")
+    assert ops.resolve_backend("auto") == ops.KernelBackend(
+        "pallas", interpret=True)
+    monkeypatch.setenv("PQ_BACKEND", "jnp")
+    assert ops.resolve_backend("auto") == ops.KernelBackend("jnp")
+    monkeypatch.setenv("PQ_BACKEND", "auto")
+    with pytest.raises(ValueError, match="PQ_BACKEND"):
+        ops.resolve_backend("auto")
+
+
+def test_pallas_raises_off_tpu(monkeypatch):
+    """Interpret mode is asked for by name, never reached by fallback."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "cpu")
+    with pytest.raises(ValueError, match="needs a TPU"):
+        ops.resolve_backend("pallas")
+    with pytest.raises(ValueError, match="needs a TPU"):
+        make_engine(_spec("pqe", backend="pallas"))
+    with pytest.raises(ValueError, match="needs a TPU"):
+        PQConfig(a_max=W, r_max=W, backend="pallas")
+
+
+@pytest.mark.parametrize("kernel", ["bitonic_sort_kvf", "merge_sorted_kvf",
+                                    "radix_select_threshold"])
+def test_kernel_entry_points_take_interpret_explicitly(kernel):
+    """No kernel entry point defaults ``interpret``: a caller picks
+    Mosaic or the interpreter, never inherits one."""
+    import inspect
+
+    from repro.kernels import bitonic, merge_consume, radix_select
+
+    mods = {"bitonic_sort_kvf": bitonic, "merge_sorted_kvf": merge_consume,
+            "radix_select_threshold": radix_select}
+    fn = inspect.unwrap(getattr(mods[kernel], kernel))
+    param = inspect.signature(fn).parameters["interpret"]
+    assert param.default is inspect.Parameter.empty
+    assert param.kind is inspect.Parameter.KEYWORD_ONLY
 
 
 def test_no_per_call_backend_strings():

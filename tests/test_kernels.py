@@ -1,5 +1,9 @@
 """Per-kernel validation: Pallas (interpret=True) vs the pure-jnp oracles,
 swept across shapes and dtypes, plus the pallas-backed tick equivalence.
+
+Off-TPU the kernels run only in interpret mode, asked for by name
+("pallas_interpret", or interpret=True at direct kernel calls): Mosaic
+refuses them for v5e (tests/test_tpu_compile.py).
 """
 
 import numpy as np
@@ -14,7 +18,7 @@ from repro.kernels.merge_consume import merge_sorted_kvf
 from repro.kernels.radix_select import radix_select_threshold
 
 # resolved ONCE, config-style — per-call backend strings are deprecated
-_PALLAS = ops.resolve_backend("pallas")
+_PALLAS = ops.resolve_backend("pallas_interpret")
 _JNP = ops.resolve_backend("jnp")
 
 
@@ -37,7 +41,7 @@ def test_bitonic_shapes(rows, n, key_dist):
     v = rng.integers(0, 1 << 20, (rows, n)).astype(np.int32)
     f = rng.integers(0, 2, (rows, n)).astype(np.int32)
     ok, ov, of = bitonic_sort_kvf(jnp.asarray(k), jnp.asarray(v),
-                                  jnp.asarray(f))
+                                  jnp.asarray(f), interpret=True)
     rk, rv, rf = ref.ref_sort_kvf(jnp.asarray(k), jnp.asarray(v),
                                   jnp.asarray(f))
     np.testing.assert_array_equal(np.asarray(ok), np.asarray(rk))
@@ -49,7 +53,7 @@ def test_bitonic_shapes(rows, n, key_dist):
 def test_bitonic_rejects_non_pow2():
     with pytest.raises(ValueError):
         bitonic_sort_kvf(jnp.zeros((1, 12)), jnp.zeros((1, 12), jnp.int32),
-                         jnp.zeros((1, 12), jnp.int32))
+                         jnp.zeros((1, 12), jnp.int32), interpret=True)
 
 
 # ---------------------------------------------------------------------------
@@ -73,7 +77,7 @@ def test_merge_shapes(n, m, tile):
     af = np.zeros(n, np.int32)
     bf = np.ones(m, np.int32)
     got = merge_sorted_kvf(*map(jnp.asarray, (a, av, af, b, bv, bf)),
-                           tile=tile)
+                           tile=tile, interpret=True)
     exp = ref.ref_merge_sorted(*map(jnp.asarray, (a, av, af, b, bv, bf)))
     for g, e in zip(got, exp):
         np.testing.assert_array_equal(
@@ -97,7 +101,7 @@ def test_merge_property(seed):
     got_k, got_v, _ = merge_sorted_kvf(
         jnp.asarray(a), jnp.asarray(av), jnp.asarray(z),
         jnp.asarray(b), jnp.asarray(bv), jnp.asarray(np.zeros(m, np.int32)),
-        tile=64)
+        tile=64, interpret=True)
     # merged keys sorted; payload multiset conserved
     gk = np.asarray(got_k)
     fin = gk[np.isfinite(gk)]
@@ -121,7 +125,8 @@ def test_radix_threshold(length):
             keys[2:6] = keys[1]   # duplicates around the threshold
         rng.shuffle(keys)
         for k in [0, 1, nfin // 2, nfin]:
-            tau, nb = radix_select_threshold(jnp.asarray(keys), k)
+            tau, nb = radix_select_threshold(jnp.asarray(keys), k,
+                                             interpret=True)
             rtau, rnb = ref.ref_select_threshold(jnp.asarray(keys), k)
             assert float(tau) == float(rtau), (length, k)
             assert int(nb) == int(rnb), (length, k)
@@ -151,13 +156,13 @@ def test_radix_threshold_edges(length):
     # k = 0 -> sentinel (-inf, 0) regardless of content
     keys = jnp.asarray(np.random.default_rng(0).uniform(
         -5, 5, length), jnp.float32)
-    tau, nb = radix_select_threshold(keys, 0)
+    tau, nb = radix_select_threshold(keys, 0, interpret=True)
     assert float(tau) == -np.inf and int(nb) == 0
 
     # all-INF stream: any k > 0 hits the INF ceiling
     inf_keys = jnp.full((length,), jnp.inf, jnp.float32)
     for k in (1, length // 2, length):
-        tau, nb = radix_select_threshold(inf_keys, k)
+        tau, nb = radix_select_threshold(inf_keys, k, interpret=True)
         assert float(tau) == np.inf and int(nb) == 0
 
     # negative keys (the float->uint32 monotone map's sign branch)
@@ -166,7 +171,8 @@ def test_radix_threshold_edges(length):
     shuffled = neg.copy()
     np.random.default_rng(2).shuffle(shuffled)
     for k in (1, 7, length):
-        tau, nb = radix_select_threshold(jnp.asarray(shuffled), k)
+        tau, nb = radix_select_threshold(jnp.asarray(shuffled), k,
+                                         interpret=True)
         assert float(tau) == neg[k - 1]
         assert int(nb) == int((neg < neg[k - 1]).sum())
 
@@ -174,15 +180,17 @@ def test_radix_threshold_edges(length):
     half = np.full(length, np.inf, np.float32)
     half[: length // 2] = np.random.default_rng(3).uniform(
         0, 10, length // 2)
-    tau, nb = radix_select_threshold(jnp.asarray(half), length)
+    tau, nb = radix_select_threshold(jnp.asarray(half), length,
+                                     interpret=True)
     assert float(tau) == np.inf and int(nb) == length // 2
 
 
 def test_radix_threshold_accepts_bucket_rows():
     rng = np.random.default_rng(5)
     k2 = rng.uniform(0, 100, (8, 32)).astype(np.float32)
-    tau2, nb2 = radix_select_threshold(jnp.asarray(k2), 17)
-    tau1, nb1 = radix_select_threshold(jnp.asarray(k2.reshape(-1)), 17)
+    tau2, nb2 = radix_select_threshold(jnp.asarray(k2), 17, interpret=True)
+    tau1, nb1 = radix_select_threshold(jnp.asarray(k2.reshape(-1)), 17,
+                                       interpret=True)
     assert float(tau2) == float(tau1) and int(nb2) == int(nb1)
 
 
@@ -230,7 +238,8 @@ def test_merge_sorted_rejects_oversized_payloads():
     ops.merge_sorted(a, ok_v, z, b, z, z, backend=_PALLAS)
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+@pytest.mark.parametrize("backend", ["jnp", "pallas_interpret"],
+                         ids=["jnp", "pallas"])
 def test_extract_k_bucketed(backend):
     """Extraction == oracle k-smallest; survivors conserve the multiset
     and keep the range partition."""
@@ -293,7 +302,7 @@ def test_tick_pallas_backend_matches_oracle():
     from repro.core import EMPTY_VAL, PQConfig, RefPQ, init, tick
     cfg = PQConfig(a_max=32, r_max=32, seq_cap=224, n_buckets=8,
                    bucket_cap=32, detach_min=4, detach_max=64,
-                   detach_init=8, backend="pallas")
+                   detach_init=8, backend="pallas_interpret")
     state = init(cfg)
     ref_pq = RefPQ()
     rng = np.random.default_rng(7)

@@ -73,3 +73,8 @@ def test_sharded_train_step_executes():
 def test_sharded_decode_executes():
     out = _run("decode")
     assert "OK sharded_decode" in out
+
+
+def test_chip_smoke_dist_phase_4dev():
+    out = _run("chip_smoke_dist")
+    assert "OK chip_smoke_dist" in out
