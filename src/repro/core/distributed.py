@@ -66,7 +66,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import sharded
+from repro.core import obs, sharded
 from repro.core.config import EMPTY_VAL, PQConfig
 from repro.core.sharded import ShardedPQConfig, ShardedState, ShardedTickResult
 from repro.dist.sharding import shard_map
@@ -265,9 +265,10 @@ def _dist_tick_body(
     # -- the tick's only collectives: per-device lane summaries -> the
     # replicated [L] vectors behind the global bound and the grant
     # allocation (O(L) scalars, independent of batch width) --
-    min_v = jax.lax.all_gather(local.min_value, axis).reshape(-1)
-    sizes_loc = local.seq_len + local.par_count
-    sizes_pre = jax.lax.all_gather(sizes_loc, axis).reshape(-1)
+    with jax.named_scope(obs.DQ_GATHER):
+        min_v = jax.lax.all_gather(local.min_value, axis).reshape(-1)
+        sizes_loc = local.seq_len + local.par_count
+        sizes_pre = jax.lax.all_gather(sizes_loc, axis).reshape(-1)
     union_min = jnp.min(min_v)
 
     # -- pre-route elimination, device-local against the replicated
@@ -295,6 +296,7 @@ def _dist_tick_body(
     # on every device (same key -> same permutation) --
     resample = (state.tick_idx % scfg.stick) == 0
 
+    @jax.named_scope(obs.SQ_ROUTE)
     def _resample(k):
         k2, sub = jax.random.split(k)
         fresh = sharded._fresh_route(sub, w, L)
@@ -588,7 +590,10 @@ class DistShardedQueue:
     ) -> Tuple[ShardedState, ShardedTickResult]:
         if lane_scale is None:
             lane_scale = self._no_scale
-        return self._tick(state, add_keys, add_vals, add_mask, rm_count, lane_scale)
+        with obs.span(obs.SPAN_TICK):
+            return self._tick(
+                state, add_keys, add_vals, add_mask, rm_count, lane_scale
+            )
 
     def tick_n(
         self,
@@ -601,7 +606,10 @@ class DistShardedQueue:
     ) -> Tuple[ShardedState, ShardedTickResult]:
         if lane_scale is None:
             lane_scale = self._no_scale
-        return self._tick_n(state, add_keys, add_vals, add_mask, rm_counts, lane_scale)
+        with obs.span(obs.SPAN_TICK_N):
+            return self._tick_n(
+                state, add_keys, add_vals, add_mask, rm_counts, lane_scale
+            )
 
     def remove_device(
         self, state: ShardedState, device: int, *, reinsert_drained: bool = True

@@ -53,7 +53,7 @@ from typing import Any, Callable, Dict, Optional, Protocol, runtime_checkable
 import jax
 import jax.numpy as jnp
 
-from repro.core import pqueue
+from repro.core import obs, pqueue
 from repro.core import sharded as shq
 from repro.core.config import PQConfig
 
@@ -274,10 +274,14 @@ class PQEngine:
         return pqueue.init(self.cfg)
 
     def tick(self, state, add_keys, add_vals, add_mask, rm_count):
-        return pqueue.tick(self.cfg, state, add_keys, add_vals, add_mask, rm_count)
+        with obs.span(obs.SPAN_TICK):
+            return pqueue.tick(self.cfg, state, add_keys, add_vals, add_mask, rm_count)
 
     def tick_n(self, state, add_keys, add_vals, add_mask, rm_counts):
-        return pqueue.tick_n(self.cfg, state, add_keys, add_vals, add_mask, rm_counts)
+        with obs.span(obs.SPAN_TICK_N):
+            return pqueue.tick_n(
+                self.cfg, state, add_keys, add_vals, add_mask, rm_counts
+            )
 
     def stats(self, state):
         return state.stats
@@ -308,10 +312,12 @@ class ShardedEngine:
         return shq.init(self.cfg, seed=seed)
 
     def tick(self, state, add_keys, add_vals, add_mask, rm_count):
-        return shq.tick(self.cfg, state, add_keys, add_vals, add_mask, rm_count)
+        with obs.span(obs.SPAN_TICK):
+            return shq.tick(self.cfg, state, add_keys, add_vals, add_mask, rm_count)
 
     def tick_n(self, state, add_keys, add_vals, add_mask, rm_counts):
-        return shq.tick_n(self.cfg, state, add_keys, add_vals, add_mask, rm_counts)
+        with obs.span(obs.SPAN_TICK_N):
+            return shq.tick_n(self.cfg, state, add_keys, add_vals, add_mask, rm_counts)
 
     def stats(self, state):
         return shq.stats(state)
@@ -345,7 +351,10 @@ class BaselineEngine:
         return self._impl.init(self.cfg)
 
     def tick(self, state, add_keys, add_vals, add_mask, rm_count):
-        return self._impl.tick(self.cfg, state, add_keys, add_vals, add_mask, rm_count)
+        with obs.span(obs.SPAN_TICK):
+            return self._impl.tick(
+                self.cfg, state, add_keys, add_vals, add_mask, rm_count
+            )
 
     def tick_n(self, state, add_keys, add_vals, add_mask, rm_counts):
         results = []

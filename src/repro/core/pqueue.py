@@ -39,6 +39,7 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import obs
 from repro.core.adaptive import update_detach
 from repro.core.config import EMPTY_VAL, PQConfig
 from repro.kernels import ops as kops
@@ -66,17 +67,12 @@ class PQStats(NamedTuple):
     n_dropped: jnp.ndarray      # items dropped at total-capacity (should be 0)
     n_ticks: jnp.ndarray
     n_removes: jnp.ndarray      # total removeMin requests (for Table 1 ratios)
-    local_elim: jnp.ndarray     # wire-avoidance metric of the retired v1
-                                # distributed tick (the lanes-over-devices
-                                # queue counts pre-interconnect matches in
-                                # ShardedStats.n_preroute_elim instead);
-                                # kept so stats pytrees stay stable
 
     @staticmethod
     def zeros() -> "PQStats":
         # distinct buffers per field: tick donates the state, and XLA
         # rejects donating one buffer twice
-        return PQStats(*(jnp.zeros((), _I32) for _ in range(15)))
+        return PQStats(*(jnp.zeros((), _I32) for _ in PQStats._fields))
 
 
 class PQState(NamedTuple):
@@ -469,6 +465,7 @@ def _scatter_fast(cfg: PQConfig, par: ParPart, keys, vals):
                    par.splitters, par_min, par_count), overflow
 
 
+@jax.named_scope(obs.PQ_HEAD)
 def _tick_head(cfg: PQConfig, state: PQState, add_keys, add_vals,
                add_mask, rm_count, *,
                adds_sorted: bool = False) -> TickMid:
@@ -549,6 +546,7 @@ def _tick_head(cfg: PQConfig, state: PQState, add_keys, add_vals,
         quiet=state.quiet_ticks, stats0=state.stats)
 
 
+@jax.named_scope(obs.PQ_COMBINE)
 def _pass_combine(cfg: PQConfig, mid: TickMid) -> TickMid:
     """Steps 3–4 as a separable pass: rank-merge the sequential part
     with the small adds, consume the remove prefix, spill past the
@@ -654,6 +652,7 @@ def _pass_combine(cfg: PQConfig, mid: TickMid) -> TickMid:
             move_off=(mid.n_imm + jnp.where(sel, s, z)).astype(_I32)))
 
 
+@jax.named_scope(obs.PQ_SCATTER)
 def _pass_scatter(cfg: PQConfig, mid: TickMid) -> TickMid:
     """Step 5 as a separable pass: SL::addPar() segment-append of the
     par-bound batch, resolving the rebalance predicate.  Lanes whose
@@ -667,6 +666,7 @@ def _pass_scatter(cfg: PQConfig, mid: TickMid) -> TickMid:
         pending=p._replace(need_rebal=sel & overflow))
 
 
+@jax.named_scope(obs.PQ_PREDS)
 def _tick_preds(cfg: PQConfig, mid: TickMid) -> TickMid:
     """Steps 6–8 predicates: moveHead shortfall, adaptive detach policy
     (paper §2.1, N=1000 / M=100 / [8, 65536]), chopHead quiet counter.
@@ -693,6 +693,7 @@ def _tick_preds(cfg: PQConfig, mid: TickMid) -> TickMid:
                            need_chop=need_chop))
 
 
+@jax.named_scope(obs.PQ_REPAIR_REBALANCE)
 def _repair_rebalance(cfg: PQConfig, mid: TickMid) -> TickMid:
     """Bucket-overflow repair: flatten + rank-merge the pending batch +
     redistribute.  Serves lanes that need a rebalance but NOT a moveHead
@@ -710,6 +711,7 @@ def _repair_rebalance(cfg: PQConfig, mid: TickMid) -> TickMid:
         n_drop_rep=mid.n_drop_rep + jnp.where(sel, dropped, 0))
 
 
+@jax.named_scope(obs.PQ_REPAIR_MOVE)
 def _repair_move(cfg: PQConfig, mid: TickMid) -> TickMid:
     """SL::moveHead() repair: selection-based extraction of the
     max(detach_n, r2) smallest parallel keys (DESIGN.md §6) — serves the
@@ -768,6 +770,7 @@ def _repair_move(cfg: PQConfig, mid: TickMid) -> TickMid:
         n_rm_par=jnp.where(sel, served, mid.n_rm_par).astype(_I32))
 
 
+@jax.named_scope(obs.PQ_REPAIR_REBAL_MOVE)
 def _repair_rebal_move(cfg: PQConfig, mid: TickMid) -> TickMid:
     """Fused rebalance + moveHead for lanes that need BOTH (the common
     case of a drain-heavy tick: this tick's adds overflowed a bucket AND
@@ -863,6 +866,7 @@ def _repair_rebal_move(cfg: PQConfig, mid: TickMid) -> TickMid:
         n_drop_rep=mid.n_drop_rep + jnp.where(sel, dropped, 0))
 
 
+@jax.named_scope(obs.PQ_REPAIR_CHOP)
 def _repair_chop(cfg: PQConfig, mid: TickMid) -> TickMid:
     """SL::chopHead() repair: rank-merge the sequential head back into
     the bucket store (both sides already sorted — no re-sort of the
@@ -882,6 +886,7 @@ def _repair_chop(cfg: PQConfig, mid: TickMid) -> TickMid:
         n_drop_rep=mid.n_drop_rep + jnp.where(sel, dropped, 0))
 
 
+@jax.named_scope(obs.PQ_FINISH)
 def _tick_finish(cfg: PQConfig, mid: TickMid) -> Tuple[PQState,
                                                        TickResult]:
     """Steps 9b–10: serve accounting, minValue/lastSeq, state assembly."""
@@ -917,7 +922,6 @@ def _tick_finish(cfg: PQConfig, mid: TickMid) -> Tuple[PQState,
         n_dropped=st.n_dropped + mid.n_drop_rep,
         n_ticks=st.n_ticks + one,
         n_removes=st.n_removes + mid.rm_count,
-        local_elim=st.local_elim,   # only the distributed wrapper adds here
     )
 
     new_state = PQState(
@@ -975,9 +979,10 @@ def _tick_impl(cfg: PQConfig, state: PQState, add_keys, add_vals,
             (p.need_move & ~p.need_rebal, _repair_move),
             (p.need_chop, _repair_chop),
         )
-    for pred, repair in repairs:
-        mid = jax.lax.cond(pred, functools.partial(repair, cfg),
-                           lambda m: m, mid)
+    with jax.named_scope(obs.PQ_PREDS):     # the repairs' dispatch
+        for pred, repair in repairs:
+            mid = jax.lax.cond(pred, functools.partial(repair, cfg),
+                               lambda m: m, mid)
     return _tick_finish(cfg, mid)
 
 

@@ -59,7 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import elimination, pqueue
+from repro.core import elimination, obs, pqueue
 from repro.core.config import EMPTY_VAL, PQConfig
 from repro.kernels import ops as kops
 from repro.kernels.radix_select import _from_sortable_u32, _to_sortable_u32
@@ -254,6 +254,7 @@ def init(cfg: ShardedPQConfig, *, seed: int = 0) -> ShardedState:
 # routing
 # ---------------------------------------------------------------------------
 
+@jax.named_scope(obs.SQ_ROUTE)
 def _fresh_route(key, w: int, n_lanes: int) -> jnp.ndarray:
     """Permuted round-robin lane map: balanced by construction (any batch
     window contains at most ceil(w / L) slots of one lane)."""
@@ -261,6 +262,7 @@ def _fresh_route(key, w: int, n_lanes: int) -> jnp.ndarray:
         key, jnp.arange(w, dtype=_I32) % n_lanes)
 
 
+@jax.named_scope(obs.SQ_ROUTE)
 def _route_adds(cfg: ShardedPQConfig, route, add_keys, add_vals, add_mask):
     """Distribute the add batch to per-lane [L, a_lane] arrays (slot
     order).
@@ -313,6 +315,7 @@ def _route_geometry(w: int, n_lanes: int):
     return idx, pad
 
 
+@jax.named_scope(obs.SQ_ROUTE)
 def _route_counts(cfg: ShardedPQConfig, route_inv, add_mask):
     """[L] live adds per lane under the current route — pure replicated
     math on the (replicated) route and mask, used by the distributed
@@ -324,6 +327,7 @@ def _route_counts(cfg: ShardedPQConfig, route_inv, add_mask):
     return jnp.sum(live, axis=-1, dtype=_I32)
 
 
+@jax.named_scope(obs.SQ_ROUTE)
 def _route_adds_sorted(cfg: ShardedPQConfig, route_inv, add_keys,
                        add_vals, add_mask, rows=None):
     """Fused router + per-lane sort via resample-amortized grouping.
@@ -377,6 +381,7 @@ def _route_adds_sorted(cfg: ShardedPQConfig, route_inv, add_keys,
     return lk, lv, taken, n_drop
 
 
+@jax.named_scope(obs.SQ_GRANTS)
 def _alloc_removes(cfg: ShardedPQConfig, lanes: pqueue.PQState, rm_count,
                    incoming=0):
     """c-relaxed min-of-lane-heads allocation of r removes to L lanes.
@@ -401,6 +406,7 @@ def _alloc_removes(cfg: ShardedPQConfig, lanes: pqueue.PQState, rm_count,
         incoming)
 
 
+@jax.named_scope(obs.SQ_GRANTS)
 def _alloc_removes_arrays(cfg: ShardedPQConfig, sizes_pre, min_value,
                           rm_count, incoming=0, grant_cap=None):
     """Array-level body of :func:`_alloc_removes`, taking the [L] lane
@@ -466,6 +472,7 @@ def _union_min(lanes: pqueue.PQState) -> jnp.ndarray:
     return jnp.min(lanes.min_value)
 
 
+@jax.named_scope(obs.SQ_PREROUTE)
 def _preroute_eliminate(cfg: ShardedPQConfig, state: ShardedState,
                         add_keys, add_vals, add_mask, rm_count,
                         union_min=None):
@@ -729,6 +736,7 @@ def _tick_impl(cfg: ShardedPQConfig, state: ShardedState, add_keys,
     # on CPU.  The rng therefore advances only on resample ticks. --
     resample = (state.tick_idx % cfg.stick) == 0
 
+    @jax.named_scope(obs.SQ_ROUTE)
     def _resample(k):
         k2, sub = jax.random.split(k)
         fresh = _fresh_route(sub, w, L)

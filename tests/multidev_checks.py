@@ -290,6 +290,38 @@ def check_chip_smoke_dist():
     print("OK chip_smoke_dist")
 
 
+def check_scopes_dist():
+    """The lanes-over-devices tick_n, compiled for 4 devices, carries
+    every pass scope and the router, pre-route, grant and lane-summary
+    gather scopes in its HLO op_name metadata, the two lane-summary
+    all-gathers under ``dq.gather``."""
+    import re
+
+    from repro.core import obs
+    from repro.core.config import PQConfig
+
+    base = PQConfig(a_max=64, r_max=64, seq_cap=512, n_buckets=16,
+                    bucket_cap=128, detach_min=4, detach_max=64,
+                    detach_init=8, chop_patience=8)
+    q = _dist_queue(4, 2, 128, base)
+    w, t = q.width, 2
+    args = (np.zeros((t, w), np.float32), np.zeros((t, w), np.int32),
+            np.zeros((t, w), bool), np.zeros((t,), np.int32),
+            np.ones((q.cfg.shard.n_lanes,), np.float32))
+    text = q._tick_n.lower(q.init(seed=0), *args).compile().as_text()
+    found = {part.rsplit("(", 1)[-1].rstrip(")")
+             for name in re.findall(r'op_name="([^"]*)"', text)
+             for part in name.split("/")} & set(obs.SCOPES)
+    assert found == set(obs.SCOPES), set(obs.SCOPES) - found
+    gathers = [re.search(r'op_name="([^"]*)"', ln).group(1)
+               for ln in text.splitlines()
+               if re.search(r"\sall-gather(-start)?\(", ln)]
+    # the two lane-summary gathers; the partitioner adds its own
+    # collectives for the result fold after shard_map (not scoped)
+    assert sum(obs.DQ_GATHER in g for g in gathers) == 2, gathers
+    print("OK scopes_dist")
+
+
 if __name__ == "__main__":
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
     checks = {
@@ -300,6 +332,7 @@ if __name__ == "__main__":
         "train": check_sharded_train_step,
         "decode": check_sharded_decode,
         "chip_smoke_dist": check_chip_smoke_dist,
+        "scopes_dist": check_scopes_dist,
     }
     _require_forced_devices()
     try:

@@ -78,3 +78,8 @@ def test_sharded_decode_executes():
 def test_chip_smoke_dist_phase_4dev():
     out = _run("chip_smoke_dist")
     assert "OK chip_smoke_dist" in out
+
+
+def test_dist_tick_n_carries_every_scope_4dev():
+    out = _run("scopes_dist")
+    assert "OK scopes_dist" in out
