@@ -19,10 +19,15 @@ DESIGN.md §2–3 for the full mapping; in brief:
 
 The hot paths are *sortless* (DESIGN.md §6): bucket ranges are disjoint
 and ordered, so moveHead is a selection (``ops.extract_k_bucketed``) and
-every merge of already-sorted streams is a rank merge
-(:func:`rank_merge_kv` / the Pallas one-hot kernel) — the only
+every merge of already-sorted streams is a rank merge — the only
 comparison sorts left are the a_max-wide add-batch sort and BCAP-wide
-per-bucket row sorts.
+per-bucket row sorts.  The combine pass's merge (``ops.merge_sorted``)
+runs every tick over the seq_cap + a_max stream, so on the jnp backend
+it has no data-dependent gather: XLA:TPU runs a gather one index at a
+time, while counted co-ranks, a static-shift placement network and
+dynamic-slice windows are elementwise work at memory speed.  The rare
+repairs merge with :func:`rank_merge_kv` (searchsorted + gather: XLA CPU
+serializes scatters); the Pallas kernel is a one-hot matmul.
 
 Correctness contract (checked against a heapq oracle in
 ``tests/test_pq_properties.py``): a tick with adds ``X`` and ``r`` removes
@@ -155,9 +160,17 @@ def _shift_left(arr, n, fill):
 
 def _take_window(arr, start, out_len, fill):
     """arr[..., start : start+out_len] with static out_len, `fill` past
-    the end.  `start` may carry leading dims matching arr's."""
+    the end; `start` >= 0.  `start` may carry leading dims matching
+    arr's: those rows take a gather.  A scalar start is one dynamic slice
+    of the row padded by out_len fill values: the same window, with no
+    gather (XLA:TPU runs a general gather per element)."""
     size = arr.shape[-1]
-    idx = jnp.expand_dims(jnp.asarray(start, _I32), -1) + jnp.arange(
+    start = jnp.asarray(start, _I32)
+    if start.ndim == 0:
+        pad = jnp.full(arr.shape[:-1] + (out_len,), fill, arr.dtype)
+        return jax.lax.dynamic_slice_in_dim(
+            jnp.concatenate([arr, pad], axis=-1), start, out_len, axis=-1)
+    idx = jnp.expand_dims(start, -1) + jnp.arange(
         out_len, dtype=_I32)
     out = jnp.take_along_axis(arr, jnp.clip(idx, 0, size - 1), axis=-1)
     return jnp.where(idx < size, out, fill)
@@ -184,8 +197,9 @@ def rank_merge_kv(ak, av, bk, bv):
     a-first), so for each output position j the source is recovered with
     one searchsorted against those ranks — all gathers, no scatter (XLA
     CPU serializes scatters; gathers vectorize), and no O((n+m) log(n+m))
-    full sort.  One implementation, shared with the kernel wrapper's jnp
-    backend; the flags lane is dead here and DCE'd under jit.
+    full sort.  The repairs' merge (n = par_cap, where compare-all
+    counting would be n * m pairs); the flags lane is dead here and
+    DCE'd under jit.
     """
     ok, ov, _ = kops._merge_sorted_corank(
         ak, av, jnp.zeros_like(av), bk, bv, jnp.zeros_like(bv))
@@ -561,8 +575,9 @@ def _pass_combine(cfg: PQConfig, mid: TickMid) -> TickMid:
     lead = mid.rm_keys.shape[:-1]
     sel = p.need_combine
 
-    # both streams are already sorted: rank-merge (co-rank gathers on
-    # the jnp backend, one-hot MXU matmul on pallas) — never a full
+    # both streams are already sorted: rank-merge (a gather-free
+    # shift network on the jnp backend, one-hot MXU matmul on pallas;
+    # the windows below are dynamic slices) — never a full
     # O(M log M) sort of seq_cap + a_max keys.  b-side flags mark the
     # small adds: one consumed inside the remove prefix eliminated
     # *after* the minimum rose past it — the batch form of the paper's
@@ -624,16 +639,18 @@ def _pass_combine(cfg: PQConfig, mid: TickMid) -> TickMid:
         jnp.where(in_lg, jnp.take_along_axis(
             p.large_v, jnp.clip(j_lg, 0, A - 1), -1), EMPTY_VAL))
 
-    # removal stream segment 2: the consumed merge prefix
+    # removal stream segment 2: the consumed merge prefix, after the
+    # n_imm <= R removes elimination served — the R-wide window at
+    # R - n_imm of [R fill | mk's first R]
     ridx = jnp.broadcast_to(jnp.arange(R, dtype=_I32), lead + (R,))
     rel = ridx - jnp.expand_dims(mid.n_imm, -1)
     in2 = ((rel >= 0) & (rel < jnp.expand_dims(s, -1))
            & jnp.expand_dims(sel, -1))
-    src2 = jnp.clip(rel, 0, M - 1)
-    rm_keys = jnp.where(in2, jnp.take_along_axis(mk, src2, -1),
-                        mid.rm_keys)
-    rm_vals = jnp.where(in2, jnp.take_along_axis(mv, src2, -1),
-                        mid.rm_vals)
+    seg = lambda x, fill: _take_window(jnp.concatenate(     # noqa: E731
+        [jnp.full(lead + (R,), fill, x.dtype), x[..., :R]], axis=-1),
+        R - mid.n_imm, R, fill)
+    rm_keys = jnp.where(in2, seg(mk, INF), mid.rm_keys)
+    rm_vals = jnp.where(in2, seg(mv, EMPTY_VAL), mid.rm_vals)
 
     z = jnp.zeros_like(s)
     return mid._replace(

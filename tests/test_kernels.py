@@ -6,7 +6,10 @@ Off-TPU the kernels run only in interpret mode, asked for by name
 refuses them for v5e (tests/test_tpu_compile.py).
 """
 
+import zlib
+
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -108,6 +111,114 @@ def test_merge_property(seed):
     assert np.all(np.diff(fin) >= 0)
     assert sorted(np.asarray(got_v).tolist()) == sorted(
         av.tolist() + bv.tolist())
+
+
+# ---------------------------------------------------------------------------
+# gather-free rank merge: the jnp path of merge_sorted
+# ---------------------------------------------------------------------------
+
+def _sorted_rows(rng, lead, width, n_fin, pool):
+    """[*lead, width] rows ascending: n_fin keys drawn from `pool`, then
+    an INF tail."""
+    k = np.full(lead + (width,), np.inf, np.float32)
+    k[..., :n_fin] = np.sort(rng.choice(pool, lead + (n_fin,)), axis=-1)
+    return k
+
+
+def _merge_case(case):
+    rng = np.random.default_rng(zlib.crc32(case.encode()))
+    pool = np.array([0.0, 1.0, 1.5, 2.0, 7.25], np.float32)
+    wide = rng.uniform(-40, 40, 64).astype(np.float32)
+    lead, n, m, na, nb = (), 96, 40, 96, 40
+    if case == "inf_tails":
+        n, m, na, nb, pool = 128, 64, 70, 23, wide
+    elif case == "all_inf_a":
+        na = 0
+    elif case == "all_inf_b":
+        nb = 0
+    elif case == "n1":
+        n, m, na, nb = 1, 33, 1, 20
+    elif case == "m1":
+        n, m, na, nb = 50, 1, 41, 1
+    elif case == "m_gt_n":
+        n, m, na, nb = 17, 200, 17, 150
+    elif case == "lane_major":
+        lead, n, m, na, nb = (3,), 64, 24, 50, 24
+    a = _sorted_rows(rng, lead, n, na, pool)
+    b = _sorted_rows(rng, lead, m, nb, pool)
+    lo = (1 << 24) - 512 if case == "payload_2_24" else 0
+    av = rng.integers(lo, 1 << 24, lead + (n,)).astype(np.int32)
+    bv = rng.integers(lo, 1 << 24, lead + (m,)).astype(np.int32)
+    af = rng.integers(lo, 1 << 24, lead + (n,)).astype(np.int32)
+    bf = rng.integers(lo, 1 << 24, lead + (m,)).astype(np.int32)
+    return a, av, af, b, bv, bf
+
+
+def _check_merge_shift(a, av, af, b, bv, bf):
+    """_merge_sorted_shift against a stable argsort of the concatenation
+    (ties a-first) and, row by row, against ref.ref_merge_sorted."""
+    got = [np.asarray(x) for x in ops._merge_sorted_shift(
+        *map(jnp.asarray, (a, av, af, b, bv, bf)))]
+    order = np.argsort(np.concatenate([a, b], -1), axis=-1, kind="stable")
+    for g, x, y in zip(got, (a, av, af), (b, bv, bf)):
+        np.testing.assert_array_equal(
+            g, np.take_along_axis(np.concatenate([x, y], -1), order, -1))
+    n, m = a.shape[-1], b.shape[-1]
+    rows = [x.reshape(-1, x.shape[-1]) for x in (a, av, af, b, bv, bf)]
+    for r in range(rows[0].shape[0]):
+        exp = ref.ref_merge_sorted(*(jnp.asarray(x[r]) for x in rows))
+        for g, e in zip(got, exp):
+            np.testing.assert_array_equal(g.reshape(-1, n + m)[r],
+                                          np.asarray(e))
+
+
+@pytest.mark.parametrize("case", [
+    "ties", "inf_tails", "all_inf_a", "all_inf_b", "n1", "m1", "m_gt_n",
+    "lane_major", "payload_2_24"])
+def test_merge_sorted_shift_matches_reference(case):
+    _check_merge_shift(*_merge_case(case))
+
+
+@given(st.integers(0, 2 ** 31 - 1))
+@settings(max_examples=10)
+def test_merge_shift_property(seed):
+    rng = np.random.default_rng(seed)
+    # a few fixed shapes: each one compiles once
+    lead, n, m = [((), 1, 7), ((), 33, 5), ((2,), 64, 64),
+                  ((), 20, 100)][seed % 4]
+    a = _sorted_rows(rng, lead, n, int(rng.integers(0, n + 1)),
+                     np.arange(6, dtype=np.float32))
+    b = _sorted_rows(rng, lead, m, int(rng.integers(0, m + 1)),
+                     np.arange(6, dtype=np.float32))
+    av = rng.integers(0, 1 << 24, lead + (n,)).astype(np.int32)
+    bv = rng.integers(0, 1 << 24, lead + (m,)).astype(np.int32)
+    _check_merge_shift(a, av, np.zeros_like(av), b, bv, np.ones_like(bv))
+
+
+def _primitive_names(jaxpr):
+    """Every primitive in a jaxpr, nested jaxprs (jit, cond) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _primitive_names(sub)
+
+
+def test_merge_sorted_jnp_has_no_gather():
+    """merge_sorted's jnp backend is the gather-free merge (no gather, no
+    searchsorted loop) and agrees with the co-rank merge the repairs
+    keep."""
+    args = tuple(map(jnp.asarray, _merge_case("ties")))
+    merge = lambda *x: ops.merge_sorted(*x, backend=_JNP)   # noqa: E731
+    names = set(_primitive_names(jax.make_jaxpr(merge)(*args).jaxpr))
+    assert {"gather", "while", "scatter"}.isdisjoint(names), names
+    corank = set(_primitive_names(
+        jax.make_jaxpr(ops._merge_sorted_corank)(*args).jaxpr))
+    assert "gather" in corank                   # the walk sees a gather
+    for g, c in zip(merge(*args), ops._merge_sorted_corank(*args)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(c))
 
 
 # ---------------------------------------------------------------------------

@@ -14,7 +14,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import (EMPTY_VAL, FCPQ, ParallelPQ, PQConfig, RefPQ, init,
                         tick)
-from repro.core.pqueue import PQState
+from repro.core.pqueue import PQState, _take_window
 
 CFG = PQConfig(a_max=32, r_max=32, seq_cap=256, n_buckets=8, bucket_cap=32,
                detach_min=4, detach_max=64, detach_init=8, chop_patience=8)
@@ -246,3 +246,26 @@ def test_elimination_stats_balanced_mix():
     total_adds = 50 * (cfg.a_max // 2)
     assert eliminated / total_adds > 0.5, (
         f"only {eliminated}/{total_adds} adds eliminated on balanced mix")
+
+
+@pytest.mark.parametrize("start", [0, 7, 24, 30, 45])
+@pytest.mark.parametrize("dtype,fill", [(jnp.float32, np.inf),
+                                        (jnp.int32, EMPTY_VAL)])
+def test_take_window_scalar_start_matches_batched(start, dtype, fill):
+    """The scalar-start window (a dynamic slice) and the batched-start one
+    (a gather) cut the same window, fill past the end included."""
+    arr = jnp.arange(2, 62, 2).astype(dtype)           # 30 slots
+    rows = jnp.stack([arr, arr + 1])
+    exp = np.full(12, fill, np.dtype(dtype))
+    inside = np.asarray(arr)[start:start + 12]
+    exp[:len(inside)] = inside
+    scalar = _take_window(arr, start, 12, fill)
+    batched = _take_window(arr[None], jnp.asarray([start]), 12, fill)[0]
+    assert scalar.dtype == batched.dtype == arr.dtype
+    np.testing.assert_array_equal(np.asarray(scalar), exp)
+    np.testing.assert_array_equal(np.asarray(batched), exp)
+    # lane-major rows under one scalar start cut every row alike
+    np.testing.assert_array_equal(
+        np.asarray(_take_window(rows, start, 12, fill)),
+        np.asarray(_take_window(rows, jnp.asarray([start, start]), 12,
+                                fill)))

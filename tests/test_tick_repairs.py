@@ -14,6 +14,8 @@ the RETURNED state must work and change nothing vs undonated use, and
 the scan driver must match eager tick-by-tick evolution exactly.
 """
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -66,6 +68,11 @@ def test_fused_lane_tick_matches_vmapped_reference(lanes):
 
     ref_tick = jax.vmap(
         lambda s, k, v, m, r: pqueue.tick(lc, s, k, v, m, r))
+    # jitted, as sharded.tick runs it: called eagerly, each lax.cond in
+    # the lane tick traces and compiles its branches again on every call
+    # and the process keeps each program's code mapped
+    lanes_tick = jax.jit(functools.partial(shq._lanes_tick, lc,
+                                           adds_sorted=True))
 
     fired = np.zeros(5, np.int64)   # combine, scatter, rebal, move, chop
     next_val = 0
@@ -97,8 +104,7 @@ def test_fused_lane_tick_matches_vmapped_reference(lanes):
             cfg, state.route_inv, ak, av, mask)
         grants = shq._alloc_removes(cfg, pre.lanes, rm,
                                     incoming=lm_s.sum(-1, dtype=jnp.int32))
-        lanes_f, res_f, _ = shq._lanes_tick(lc, pre.lanes, lk_s, lv_s,
-                                            lm_s, grants, adds_sorted=True)
+        lanes_f, res_f, _ = lanes_tick(pre.lanes, lk_s, lv_s, lm_s, grants)
 
         # reference: slot-order routing, every lane a full vmapped tick
         lk_r, lv_r, lm_r, _ = shq._route_adds(cfg, state.route, ak, av,

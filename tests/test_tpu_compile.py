@@ -20,7 +20,9 @@ test file.  The persistent compilation cache is off for these compiles
 """
 
 import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -117,11 +119,73 @@ def _sharded_tick_n(cfg, sharding):
 # the jnp tick: the chip path, at chip_smoke.py's shapes
 # ---------------------------------------------------------------------------
 
-def test_pqe_tick_n_compiles_at_production(one_chip):
+@pytest.fixture(scope="module")
+def pqe_production(one_chip):
+    """The pqe chip path at PRODUCTION geometry, compiled once."""
     cfg = make_engine(EngineSpec(engine="pqe", width=1024,
                                  base=PRODUCTION)).cfg
     assert not cfg.backend.is_pallas
-    _fits("pqe", _pqe_tick_n(cfg, one_chip))
+    return cfg, _pqe_tick_n(cfg, one_chip)
+
+
+def test_pqe_tick_n_compiles_at_production(pqe_production):
+    _fits("pqe", pqe_production[1])
+
+
+_HLO_INSTR = re.compile(
+    r"^\s*(?:ROOT\s+)?(%\S+) = (.*?) ([a-z][\w-]*)\((.*)$")
+
+
+def _elems(shape: str) -> int:
+    """Elements of the largest array in an HLO shape (tuples included)."""
+    return max((math.prod(int(d) for d in dims.split(",") if d)
+                for dims in re.findall(r"[a-z]\w*\[([\d,]*)\]", shape)),
+               default=0)
+
+
+def _wide_ops_in_scope(hlo_text: str, scope: str, min_elems: int):
+    """(op, name, elements) of each gather whose operand, and each while
+    whose state, holds an array of min_elems or more, in `scope`."""
+    shapes, found = {}, []
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTR.match(line)
+        if not m:
+            continue
+        name, shape, op, rest = m.groups()
+        shapes[name] = shape
+        if op not in ("gather", "while") or f"/{scope}/" not in rest:
+            continue
+        wide = (_elems(shapes.get(rest.split(",")[0].strip(), ""))
+                if op == "gather" else _elems(shape))
+        if wide >= min_elems:
+            found.append((op, name, wide))
+    return found
+
+
+def test_pqe_combine_has_no_wide_gather_or_loop(pqe_production):
+    """The combine pass at PRODUCTION merges and cuts its windows with
+    compares, static shifts and dynamic slices: no gather over the
+    seq_cap-wide head or merged stream, and no searchsorted loop."""
+    cfg, compiled = pqe_production
+    text = compiled.as_text()
+    assert "/pq.combine/" in text
+    assert _wide_ops_in_scope(text, "pq.combine", cfg.seq_cap) == []
+
+
+def test_wide_op_guard_sees_a_gather_and_a_search_loop(one_chip):
+    """The guard above finds what it looks for: a take_along_axis and a
+    searchsorted under the scope, compiled for the chip."""
+    @jax.jit
+    def f(x, i):
+        with jax.named_scope("pq.combine"):
+            return (jnp.take_along_axis(x, i, axis=-1),
+                    jnp.searchsorted(x, x[::2]))
+
+    x = jax.ShapeDtypeStruct((4096,), jnp.float32, sharding=one_chip)
+    i = jax.ShapeDtypeStruct((4096,), jnp.int32, sharding=one_chip)
+    found = _wide_ops_in_scope(f.lower(x, i).compile().as_text(),
+                               "pq.combine", 4096)
+    assert {op for op, _, _ in found} == {"gather", "while"}, found
 
 
 def test_sharded_l8_tick_n_compiles_at_w8192(one_chip):
